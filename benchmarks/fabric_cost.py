@@ -81,6 +81,7 @@ def _claim_rows() -> list[dict]:
 _MEASURE_SRC = textwrap.dedent("""
     import os, time
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import numpy as np
     from repro.core import collectives as C
     from repro.launch.mesh import make_mesh
@@ -104,17 +105,16 @@ def _measured_rows() -> list[dict]:
     ratio is reported, not checked — the point is that both numbers come
     from the SAME schedule object.
     """
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    try:
-        proc = subprocess.run([sys.executable, "-c", _MEASURE_SRC],
-                              capture_output=True, text=True, env=env,
-                              timeout=300)
-        measured = float(proc.stdout.strip().splitlines()[-1])
-    except Exception:
-        return [{"bench": "fabric_cost", "metric": "measured_skipped",
-                 "value": 1, "note": "8-device host measurement unavailable"}]
+    proc = subprocess.run([sys.executable, "-c", _MEASURE_SRC],
+                          capture_output=True, text=True, env=env,
+                          timeout=300)
+    if proc.returncode != 0:
+        raise RuntimeError("8-host-device ring measurement failed:\n"
+                           + proc.stderr[-4000:])
+    measured = float(proc.stdout.strip().splitlines()[-1])
     sched = fabric.lower_all_reduce(Torus((8,)), ("x",))
     pred = fabric.estimate(sched, 4 * MiB).total_s
     return [

@@ -16,6 +16,7 @@ import os
 
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"   # forced host devices, never the chip
 
 import dataclasses  # noqa: E402
 

@@ -46,7 +46,7 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import fabric, jaxcompat
+from repro.core import fabric
 from repro.core.fabric import CollectiveSchedule
 # Re-exported executor helpers: the implementations (and all ring/hop math)
 # live in core/fabric; these names are long-standing public API here.
@@ -57,7 +57,7 @@ from repro.core.topology import Torus
 
 def _axis_torus(axis_names: Sequence[str]) -> Torus:
     """The ring/torus implied by the bound mesh axes (trace-time static)."""
-    return Torus(tuple(jaxcompat.axis_size(ax) for ax in axis_names))
+    return Torus(tuple(jax.lax.axis_size(ax) for ax in axis_names))
 
 
 # ----------------------------------------------------------------------------
@@ -224,8 +224,8 @@ def make_stacked_all_reduce(mesh: Mesh, axis_names: Sequence[str], *,
         return out.reshape(x.shape)
 
     spec = P(*axes)
-    mapped = jaxcompat.shard_map(per_shard, mesh=mesh, in_specs=(spec,),
-                                 out_specs=spec)
+    mapped = jax.shard_map(per_shard, mesh=mesh, in_specs=(spec,),
+                           out_specs=spec, check_vma=False)
     return jax.jit(mapped)
 
 

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core import jaxcompat
 from repro.core.fabric.schedule import (
     A2A, AG, AR, HALO, RS, BucketPlan, CollectiveSchedule, Phase)
 
@@ -58,13 +57,44 @@ def _flatten_pad(x: jax.Array, n: int) -> tuple[jax.Array, int]:
     return flat, flat.size // n
 
 
+LANES = 128   # TPU vector lane width
+
+
+def _halves(x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Front and back halves of every row of ``x`` (rows, n).
+
+    When n is a whole number of 2 x 128 lanes the halves come back as
+    (rows, n // 256, 128) views sliced on a major dim: slicing inside the
+    minor dim of a (rows, n) array makes the TPU compiler take minutes once
+    n reaches embedding-table sizes (tens of millions)."""
+    rows, n = x.shape
+    if n % (2 * LANES) == 0:
+        v = x.reshape(rows, 2, n // (2 * LANES), LANES)
+        return v[:, 0], v[:, 1]
+    half = n // 2
+    return x[:, :half], x[:, half:]
+
+
+def _join(front: jax.Array, back: jax.Array) -> jax.Array:
+    """Inverse of _halves: each row's front then back half, as (rows, n)."""
+    if front.ndim == 3:
+        return jnp.stack([front, back], axis=1).reshape(front.shape[0], -1)
+    return jnp.concatenate([front, back], axis=-1)
+
+
+def _lanes(x: jax.Array) -> jax.Array:
+    """(rows, n) as (rows, n // 128, 128) when lane-aligned (see _halves)."""
+    rows, n = x.shape
+    return x.reshape(rows, n // LANES, LANES) if n % LANES == 0 else x
+
+
 def ring_slot(phase: Phase, axis_name: str | None = None):
     """This rank's slot on the phase ring (traced; = axis index when the
     ring is the identity).  Ranks at dead positions get slot 0 — their
     output is undefined, they send nothing and receive zeros."""
     axis = axis_name or phase.axis
     pos = lax.axis_index(axis)
-    n = jaxcompat.axis_size(axis)
+    n = lax.axis_size(axis)
     if phase.ring == tuple(range(n)):
         return pos
     inv = np.zeros((n,), np.int32)
@@ -82,8 +112,8 @@ def _phase_perms(phase: Phase) -> list[list[tuple[int, int]]]:
 # ----------------------------------------------------------------------------
 
 def _rs_directed(acc, axis: str, perm, slot, m: int, sgn: int, nsteps: int):
-    """One directed ring pass over ``acc`` of shape (m, chunk); returns the
-    fully reduced chunk owned by this rank's slot."""
+    """One directed ring pass over ``acc`` of shape (m, *chunk); returns
+    the fully reduced chunk owned by this rank's slot."""
     def body(s, acc):
         send_idx = (slot - sgn * (s + 1)) % m
         recv_idx = (slot - sgn * (s + 2)) % m
@@ -136,12 +166,13 @@ def _exec_rs_phase(work: jax.Array, phase: Phase) -> jax.Array:
     perms = _phase_perms(phase)
     nsteps = len(phase.steps)
     if phase.directions == 2:
-        half = chunk // 2
-        out_f, out_b = _rs_bidi(acc[:, :half], acc[:, half:], phase.axis,
+        acc_f, acc_b = _halves(acc)
+        out_f, out_b = _rs_bidi(acc_f, acc_b, phase.axis,
                                 perms[0], perms[1], slot, m, nsteps)
-        out = jnp.concatenate([out_f, out_b], axis=0)
+        out = _join(out_f[None], out_b[None])[0]
     else:
-        out = _rs_directed(acc, phase.axis, perms[0], slot, m, +1, nsteps)
+        out = _rs_directed(_lanes(acc), phase.axis, perms[0], slot, m, +1,
+                           nsteps).reshape(-1)
     return out / m if phase.mean else out
 
 
@@ -196,11 +227,13 @@ def _exec_ag_phase(work: jax.Array, phase: Phase) -> jax.Array:
     perms = _phase_perms(phase)
     nsteps = len(phase.steps)
     if phase.directions == 2:
-        half = flat.size // 2
-        out_f, out_b = _ag_bidi(flat[:half], flat[half:], phase.axis,
+        x_f, x_b = _halves(flat[None])
+        out_f, out_b = _ag_bidi(x_f[0], x_b[0], phase.axis,
                                 perms[0], perms[1], slot, m, nsteps)
-        return jnp.concatenate([out_f, out_b], axis=-1)
-    return _ag_directed(flat, phase.axis, perms[0], slot, m, +1, nsteps)
+        return _join(out_f, out_b)
+    out = _ag_directed(_lanes(flat[None])[0], phase.axis, perms[0], slot, m,
+                       +1, nsteps)
+    return out.reshape(m, -1)
 
 
 # ----------------------------------------------------------------------------

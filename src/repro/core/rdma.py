@@ -26,7 +26,7 @@ from typing import Sequence
 import jax
 from jax import lax
 
-from repro.core import apelink, jaxcompat
+from repro.core import apelink
 from repro.core.fabric.qos import TrafficClass
 from repro.core.tlb import PAGE_BYTES, Tlb
 from repro.core.topology import Torus
@@ -41,7 +41,7 @@ def put_shift(x: jax.Array, axis_name: str, step: int = +1) -> jax.Array:
 
     Multi-hop |step| is realised as |step| single-hop writes (neighbour
     links are the only physical channels on the torus)."""
-    n = jaxcompat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     hop = +1 if step >= 0 else -1
     perm = [(i, (i + hop) % n) for i in range(n)]
     for _ in range(abs(step)):

@@ -3,8 +3,8 @@
 Dispatch policy (``impl=`` argument, default "auto"):
 
   * "pallas"   — the Pallas kernel, compiled for TPU (or interpret=True when
-                 the backend is CPU, so CI on this container still exercises
-                 the kernel body);
+                 the backend is CPU, so CPU test runs still exercise the
+                 kernel body; any other backend raises);
   * "ref"      — the pure-jnp sequential oracle ("pertoken" for the scans).
                  GSPMD-shardable but per-token state traffic (the dry-run
                  baseline);
@@ -23,8 +23,6 @@ from typing import Literal
 
 import jax
 
-from repro.core import jaxcompat
-
 from repro.kernels import flash_attention as _fa
 from repro.kernels import mamba2_scan as _m2
 from repro.kernels import paged_attention as _pa
@@ -35,13 +33,22 @@ Impl = Literal["auto", "pallas", "ref", "pertoken", "chunked"]
 
 
 def _use_pallas(impl: Impl) -> tuple[bool, bool]:
-    """Returns (use_pallas, interpret)."""
+    """Returns (use_pallas, interpret).
+
+    Interpret mode is for the CPU only: asking for the kernel on any other
+    backend that is not a TPU raises instead of silently interpreting."""
     if impl in ("ref", "pertoken", "chunked"):
         return False, False
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "pallas":
-        return True, not on_tpu
-    return (True, False) if on_tpu else (False, False)
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return True, False
+    if impl == "auto":
+        return False, False
+    if backend != "cpu":
+        raise NotImplementedError(
+            f"Pallas kernels compile for TPU and interpret on CPU only; "
+            f"backend is {backend!r}")
+    return True, True
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale=None,
@@ -105,8 +112,9 @@ def sharded_flash_attention(mesh, *, data_axes=("data",), model_axis="model",
     spec = P(tuple(data_axes), model_axis, None, None)
 
     fn = functools.partial(flash_attention, **kw)
-    return jaxcompat.shard_map(lambda q, k, v: fn(q, k, v), mesh=mesh,
-                               in_specs=(spec, spec, spec), out_specs=spec)
+    return jax.shard_map(lambda q, k, v: fn(q, k, v), mesh=mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)
 
 
 def sharded_paged_attention(mesh, *, data_axes=("data",), model_axis="model",
@@ -118,6 +126,7 @@ def sharded_paged_attention(mesh, *, data_axes=("data",), model_axis="model",
     lspec = P(tuple(data_axes))
 
     fn = functools.partial(paged_attention, **kw)
-    return jaxcompat.shard_map(
+    return jax.shard_map(
         lambda q, kp, vp, pt, sl: fn(q, kp, vp, pt, sl), mesh=mesh,
-        in_specs=(qspec, kvspec, kvspec, tspec, lspec), out_specs=qspec)
+        in_specs=(qspec, kvspec, kvspec, tspec, lspec), out_specs=qspec,
+        check_vma=False)

@@ -16,9 +16,14 @@ XLA-level gather materialising the sequence first.
 benchmarks/tlb.py quantifies the byte-traffic gap between the two paths
 (the gather path writes the gathered copy back to HBM before attending).
 
-Grid: (B, H, max_pages), page axis innermost/sequential; online-softmax
-running stats in VMEM scratch; pages past a sequence's length are skipped
-(pl.when), so ragged batches pay only for resident pages.
+Grid: (B, max_pages), page axis innermost/sequential.  One step streams a
+whole physical page for ALL KV heads — block (1, page, Hkv, D), whose two
+trailing dims equal the pool's, as the TPU tiling rule requires — and each
+KV head serves its ``H/Hkv`` query heads inside the kernel (queries are
+viewed as (B, Hkv, group, D) so a KV head's queries are one leading-dim
+slice).  Online-softmax running stats live in VMEM scratch; pages past a
+sequence's length are skipped (pl.when), so ragged batches pay only for
+resident pages.
 """
 from __future__ import annotations
 
@@ -35,10 +40,10 @@ NEG_INF = -1e30
 def _kernel(page_table_ref, seq_lens_ref,      # scalar-prefetch operands
             q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *,
-            scale: float, page: int):
+            scale: float, page: int, n_kv: int, group: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    npages = pl.num_programs(2)
+    j = pl.program_id(1)
+    npages = pl.num_programs(1)
     seq_len = seq_lens_ref[b]
 
     @pl.when(j == 0)
@@ -50,24 +55,30 @@ def _kernel(page_table_ref, seq_lens_ref,      # scalar-prefetch operands
     # The page is resident iff it holds any position < seq_len.
     @pl.when(j * page < seq_len)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (D,)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (page, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (page, D)
-        s = jnp.einsum("d,pd->p", q, k)                      # (page,)
-        pos = j * page + jax.lax.iota(jnp.int32, page)
-        s = jnp.where(pos < seq_len, s, NEG_INF)
-        m_prev = m_ref[0]
-        m_new = jnp.maximum(m_prev, s.max())
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[0] = alpha * l_ref[0] + p.sum()
-        m_ref[0] = m_new
-        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum("p,pd->d", p, v)[None]
+        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (group, page), 1)
+        valid = pos < seq_len
+        for g in range(n_kv):                         # static: one KV head
+            q = q_ref[0, g].astype(jnp.float32) * scale        # (group, D)
+            k = k_ref[0, :, g, :].astype(jnp.float32)          # (page, D)
+            v = v_ref[0, :, g, :].astype(jnp.float32)          # (page, D)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(valid, s, NEG_INF)                   # (group, page)
+            m_prev = m_ref[g]                                  # (group, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[g] = alpha * l_ref[g] + p.sum(axis=-1, keepdims=True)
+            m_ref[g] = m_new
+            acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(j == npages - 1)
     def _flush():
-        denom = jnp.where(l_ref[0] == 0.0, 1.0, l_ref[0])
-        o_ref[0, 0, :] = (acc_ref[0] / denom).astype(o_ref.dtype)
+        l = l_ref[...]
+        denom = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -83,30 +94,33 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     group = H // Hkv
     scale = scale if scale is not None else D ** -0.5
 
-    kernel = functools.partial(_kernel, scale=scale, page=page)
+    kernel = functools.partial(_kernel, scale=scale, page=page, n_kv=Hkv,
+                               group=group)
+    # query head h = g * group + i serves KV head g (the repeat order of
+    # ref.paged_attention)
+    qg = q.reshape(B, Hkv, group, D)
+    head_spec = pl.BlockSpec((1, Hkv, group, D),
+                             lambda b, j, pt, sl: (b, 0, 0, 0))
+    # THE TLB: physical page id comes from the prefetched page table.
+    page_spec = pl.BlockSpec((1, page, Hkv, D),
+                             lambda b, j, pt, sl: (pt[b, j], 0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, D), lambda b, h, j, pt, sl: (b, h, 0)),
-            # THE TLB: physical page id comes from the prefetched page table.
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, j, pt, sl: (pt[b, j], 0, h // group, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, j, pt, sl: (pt[b, j], 0, h // group, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, j, pt, sl: (b, h, 0)),
+        grid=(B, max_pages),
+        in_specs=[head_spec, page_spec, page_spec],
+        out_specs=head_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((Hkv, group, D), jnp.float32),
+            pltpu.VMEM((Hkv, group, 1), jnp.float32),
+            pltpu.VMEM((Hkv, group, 1), jnp.float32),
         ],
     )
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
         interpret=interpret,
-    )(page_table, seq_lens, q, k_pages, v_pages)
+    )(page_table, seq_lens, qg, k_pages, v_pages)
+    return out.reshape(B, H, D)

@@ -1,6 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines: jax locks the device count on first init.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST be the first lines: jax locks the platform and device count on
+# first init, and the 512 forced host devices are CPU devices.
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this script builds the real step function (train / prefill /

@@ -13,8 +13,8 @@ the real single-CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.core import jaxcompat
 from repro.core.topology import Torus
 
 POD_AXES = ("data", "model")
@@ -30,7 +30,9 @@ def make_mesh(shape, axes, *, devices=None) -> jax.sharding.Mesh:
     need = int(np.prod(tuple(shape)))
     if devices is None and len(jax.devices()) > need:
         devices = jax.devices()[:need]
-    return jaxcompat.make_mesh(tuple(shape), tuple(axes), devices=devices)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

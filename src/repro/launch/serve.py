@@ -33,8 +33,11 @@ def main(argv=None) -> int:
     import jax  # noqa: E402
 
     from repro import configs  # noqa: E402
+    from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
     from repro.models import api  # noqa: E402
     from repro.serving.engine import Engine, PagedLM, Request  # noqa: E402
+
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch)
     if args.reduced:

@@ -47,14 +47,18 @@ def main(argv=None) -> int:
         os.environ.setdefault(
             "XLA_FLAGS",
             f"--xla_force_host_platform_device_count={args.devices}")
+        os.environ["JAX_PLATFORMS"] = "cpu"   # host devices, not the chip
 
     import jax  # noqa: E402  (after XLA_FLAGS)
     import numpy as np  # noqa: E402
 
     from repro import configs  # noqa: E402
+    from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
     from repro.launch.mesh import make_mesh  # noqa: E402
     from repro.optim import AdamWConfig  # noqa: E402
     from repro.runtime.trainer import Trainer, TrainerConfig  # noqa: E402
+
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch)
     if args.reduced:

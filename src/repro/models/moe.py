@@ -20,7 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import jaxcompat
 from repro.models.common import ArchCfg, dense_init
 
 
@@ -201,7 +200,7 @@ def apply_moe_ep(cfg: ArchCfg, p, x):
     in_specs = (P(tuple(dpx), "model", None), P(), P("model", None, None),
                 P("model", None, None), P("model", None, None))
     out_specs = (P(tuple(dpx), "model", None), P())
-    mapped = jaxcompat.shard_map(local, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     y, aux = mapped(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y, jnp.mean(aux)

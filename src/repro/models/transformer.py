@@ -18,7 +18,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core import jaxcompat
 from repro.kernels import ref as kref
 from repro.models import attention as attn
 from repro.models import common, moe
@@ -197,8 +196,8 @@ def _stack_manual_sp(cfg: ArchCfg, layers, h, *, remat: bool):
 
     lspecs = jax.tree_util.tree_map_with_path(leaf_spec, layers)
     hspec = P(tuple(dpx), "model", None)
-    mapped = jaxcompat.shard_map(stack, mesh=mesh, in_specs=(hspec, lspecs),
-                                 out_specs=hspec, check_vma=False)
+    mapped = jax.shard_map(stack, mesh=mesh, in_specs=(hspec, lspecs),
+                           out_specs=hspec, check_vma=False)
     return mapped(h, layers), jnp.zeros((), jnp.float32)
 
 

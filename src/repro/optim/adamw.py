@@ -138,8 +138,7 @@ def apex_zero1_update(cfg: AdamWConfig, grads, state, params, *,
         if pre_reduced:
             # bucket hook already ran the ring RS inside backward: slice
             # this rank's chunk (the rest of the buffer is zeros)
-            from repro.core import jaxcompat as _jc
-            n_ = _jc.axis_size(axis_name)
+            n_ = jax.lax.axis_size(axis_name)
             chunk_ = m.shape[0]
             gflat = g.reshape(-1).astype(jnp.float32)
             gshard = jax.lax.dynamic_slice(
@@ -154,8 +153,7 @@ def apex_zero1_update(cfg: AdamWConfig, grads, state, params, *,
         v = b2 * v + (1 - b2) * gshard * gshard
         delta = (m / bc1) / (jnp.sqrt(v / bc2) + cfg.eps)
         # matching param shard
-        from repro.core import jaxcompat
-        n = jaxcompat.axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         chunk = m.shape[0]
         r = jax.lax.axis_index(axis_name)
         pshard = jax.lax.dynamic_slice(

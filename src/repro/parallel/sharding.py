@@ -28,14 +28,17 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.jaxcompat import abstract_mesh  # noqa: F401  (re-export:
-# spec-level tests build device-less production meshes through here)
 from repro.models.common import ArchCfg
 
 STACKED_KEYS = {"layers", "mamba", "enc_layers", "dec_layers"}
 MOE_EXPERT_KEYS = {"w_gate", "w_up", "w_down"}
+
+
+def abstract_mesh(shape, axes) -> AbstractMesh:
+    """Device-less mesh: spec-level tests build production meshes here."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def dp_axes(mesh: Mesh, cfg: ArchCfg | None = None) -> tuple[str, ...]:

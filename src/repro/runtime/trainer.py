@@ -34,7 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointStore
 from repro.core import collectives as C
-from repro.core import fabric, hw, jaxcompat
+from repro.core import fabric, hw
 from repro.core.lofamo import LofamoSim
 from repro.core.rdma import RdmaEndpoint
 from repro.core.topology import Torus
@@ -349,13 +349,13 @@ class Trainer:
         out_specs = (P(), P(axis), P(axis), P(), P())
         # check_vma off: outputs ARE replicated (post all-gather), but the
         # ppermute chain hides that from the varying-axes checker.
-        self._apex_step = jax.jit(jaxcompat.shard_map(
+        self._apex_step = jax.jit(jax.shard_map(
             make_per_shard(overlap), mesh=mesh, in_specs=in_specs,
             out_specs=out_specs, check_vma=False))
         self._apex_step_seq = None
         self._apex_compute_fn = None
         if overlap:
-            self._apex_step_seq = jax.jit(jaxcompat.shard_map(
+            self._apex_step_seq = jax.jit(jax.shard_map(
                 make_per_shard(False), mesh=mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False))
 
@@ -370,7 +370,7 @@ class Trainer:
                            for g in jax.tree.leaves(grads))
                 return jnp.stack([loss, keep])[None]
 
-            self._apex_compute_fn = jax.jit(jaxcompat.shard_map(
+            self._apex_compute_fn = jax.jit(jax.shard_map(
                 grads_only, mesh=mesh, in_specs=(P(), P(axis)),
                 out_specs=P(axis), check_vma=False))
 

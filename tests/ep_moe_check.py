@@ -8,6 +8,7 @@ y_t = sum_k p_k FFN_{e_k}(x_t) computed directly per token.
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import dataclasses  # noqa: E402
 

@@ -12,13 +12,14 @@ Covers the acceptance bar for the fabric refactor:
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.core import collectives as C  # noqa: E402
-from repro.core import fabric, jaxcompat  # noqa: E402
+from repro.core import fabric  # noqa: E402
 from repro.core.topology import Torus  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 
@@ -41,8 +42,9 @@ def run_sharded(mesh, axes, fn, x):
     def per_shard(v):
         return fn(v.reshape(v.shape[lead:])).reshape(v.shape)
 
-    return np.asarray(jax.jit(jaxcompat.shard_map(
-        per_shard, mesh=mesh, in_specs=(spec,), out_specs=spec))(x))
+    return np.asarray(jax.jit(jax.shard_map(
+        per_shard, mesh=mesh, in_specs=(spec,), out_specs=spec,
+        check_vma=False))(x))
 
 
 def all_reduce_checks(rng):
@@ -92,8 +94,8 @@ def chunk_ownership_check(rng):
         out, _ = fabric.execute_reduce_scatter(sched, v[0])
         return out[None]
 
-    h = jax.jit(jaxcompat.shard_map(rs_only, mesh=mesh, in_specs=(P("x"),),
-                                    out_specs=P("x")))
+    h = jax.jit(jax.shard_map(rs_only, mesh=mesh, in_specs=(P("x"),),
+                              out_specs=P("x"), check_vma=False))
     chunks = np.asarray(h(x))
     np.testing.assert_allclose(chunks, x.sum(0).reshape(8, 8),
                                rtol=2e-5, atol=1e-5)
@@ -109,8 +111,9 @@ def a2a_and_halo_checks(rng):
     def a2a(v):
         return fabric.execute_all_to_all(sched, v[0])[None]
 
-    out = np.asarray(jax.jit(jaxcompat.shard_map(
-        a2a, mesh=mesh, in_specs=(P("x"),), out_specs=P("x")))(xa))
+    out = np.asarray(jax.jit(jax.shard_map(
+        a2a, mesh=mesh, in_specs=(P("x"),), out_specs=P("x"),
+        check_vma=False))(xa))
     np.testing.assert_allclose(out, xa.transpose(1, 0, 2), rtol=1e-6)
     check("all-to-all schedule == transpose")
 
@@ -121,8 +124,9 @@ def a2a_and_halo_checks(rng):
         prev, nxt = fabric.execute_halo_exchange(hs, v[0], halo=2)
         return jax.numpy.stack([prev, nxt])[None]
 
-    out = np.asarray(jax.jit(jaxcompat.shard_map(
-        halo, mesh=mesh, in_specs=(P("x"),), out_specs=P("x")))(xh))
+    out = np.asarray(jax.jit(jax.shard_map(
+        halo, mesh=mesh, in_specs=(P("x"),), out_specs=P("x"),
+        check_vma=False))(xh))
     for r in range(8):
         np.testing.assert_allclose(out[r, 0], xh[(r - 1) % 8][-2:], rtol=1e-6)
         np.testing.assert_allclose(out[r, 1], xh[(r + 1) % 8][:2], rtol=1e-6)
@@ -208,7 +212,7 @@ def bucket_hook_equivalence_checks(rng):
                          for x in list(bucketed) + seq)
 
         spec = P(*axes)
-        out = jax.jit(jaxcompat.shard_map(
+        out = jax.jit(jax.shard_map(
             per_shard, mesh=mesh, in_specs=(spec,) * len(leaves),
             out_specs=(spec,) * (2 * len(leaves)),
             check_vma=False))(*leaves)

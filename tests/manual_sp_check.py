@@ -3,6 +3,7 @@ check on 8 forced host devices (launched by tests/test_manual_sp.py)."""
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import dataclasses  # noqa: E402
 

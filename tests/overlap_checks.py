@@ -14,6 +14,7 @@ Acceptance bar for the overlap engine's apex path:
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import tempfile  # noqa: E402
 

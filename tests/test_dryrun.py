@@ -5,6 +5,7 @@ gate the machinery itself: one real cell on the 512-chip multi-pod mesh in
 a subprocess (forced host devices), plus the cell-enumeration logic.
 """
 import json
+import os
 import subprocess
 import sys
 
@@ -41,7 +42,8 @@ def test_dryrun_cell_multipod(tmp_path):
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "smollm-135m", "--shape", "train_4k",
          "--mesh", "multipod", "--force", "--out", str(tmp_path)],
-        capture_output=True, text=True, timeout=1200)
+        capture_output=True, text=True, timeout=1200,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert r.returncode == 0, r.stdout + r.stderr
     out = json.loads(
         (tmp_path / "smollm-135m_train_4k_multipod.json").read_text())

@@ -416,6 +416,7 @@ def test_lofamo_fault_map_drives_rewrite():
 def test_fabric_multidevice_equivalence():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "fabric_checks.py")],

@@ -11,6 +11,7 @@ import pytest
 def test_manual_sp_multidevice():
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               JAX_PLATFORMS="cpu",
                PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
     r = subprocess.run(
         [sys.executable, str(Path(__file__).parent / "manual_sp_check.py")],

@@ -262,6 +262,7 @@ def test_overlap_trainer_multidevice_equivalence():
     survives a link-fault reroute — see tests/overlap_checks.py."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "overlap_checks.py")],
