@@ -120,5 +120,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

@@ -106,6 +106,7 @@ def mamba2_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bmat: jax.Array,
         out_specs=seq,
         out_shape=jax.ShapeDtypeStruct((Bsz, H, S, dh), x.dtype),
         scratch_shapes=[pltpu.VMEM((ds, dh), jnp.float32)],
+        name="mamba2_scan",
         interpret=interpret,
     )(x.transpose(0, 2, 1, 3), cum[..., None], cum[:, :, None, :],
       dth[..., None], dth[:, :, None, :], Bmat, Cmat,

@@ -121,6 +121,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+        name="paged_attention",
         interpret=interpret,
     )(page_table, seq_lens, qg, k_pages, v_pages)
     return out.reshape(B, H, D)

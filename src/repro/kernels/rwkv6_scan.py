@@ -87,6 +87,7 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, H, S, dh), r.dtype),
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)]
         + [pltpu.VMEM((chunk, dh), jnp.float32)] * 5,
+        name="rwkv6_scan",
         interpret=interpret,
     )(*(t.transpose(0, 2, 1, 3) for t in (r, k, v, w)), u.reshape(H, 1, dh))
     return y.transpose(0, 2, 1, 3)

@@ -14,9 +14,19 @@ walk).  Continuous batching: finished requests free their pages; admitted
 requests prefill into freshly mapped ones.
 
 Engine scope: decoder-only transformer families (dense/moe/vlm).
+
+The serving loop marks its work with profiler spans (``span``), on the
+same clock as the device trace: ``engine/step`` around each engine step
+and, inside it, each slot claim, prefill chunk, decode dispatch, token
+readback and slot release, with the request, slot and step they serve as
+arguments.  The jitted decode and prefill-chunk programs name their parts
+with ``jax.named_scope`` (``embed``; per layer ``qkv``, ``kv_write``,
+``attention``, ``attn_out``, ``mlp``; ``head``), which the device ops
+carry in their metadata.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 
@@ -36,6 +46,15 @@ from repro.models import common
 from repro.models import moe as moe_mod
 from repro.models import transformer
 from repro.models.common import ArchCfg
+
+SPAN_PREFIX = "engine/"
+SPANS = ("step", "claim", "prefill_chunk", "decode", "sample", "retire")
+
+
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """The profiler span ``engine/<name>`` (one of ``SPANS``) carrying
+    ``args``; about a microsecond when no profiler is recording."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **args)
 
 
 class TruncatedRunError(RuntimeError):
@@ -75,6 +94,7 @@ class Request:
     shed_s: float | None = None        # admission gave up (SLO shed)
     warm_tokens: int = 0
     session: int = -1                  # trace session id (-1: none)
+    submitted: float | None = None     # perf_counter at Engine.submit
 
     @property
     def done(self) -> bool:
@@ -242,6 +262,7 @@ class PagedLM:
             self.tp_step_bytes = 0
             self.predicted_tp_comm_s = 0.0
         self.slot_pages: dict[int, list[int]] = {}
+        self.steps = 0               # decode steps run (``engine/decode``)
         if modelled:
             self._decode = None
             self._prefill = None
@@ -414,7 +435,8 @@ class PagedLM:
         hd = cfg.resolved_head_dim
         group = cfg.n_heads // cfg.n_kv_heads
         S_all = self.pages_per_seq * self.page
-        h = common.embed_tokens(params["embed"], tokens)
+        with jax.named_scope("embed"):
+            h = common.embed_tokens(params["embed"], tokens)
         freqs = common.rope_freqs(cfg)
         pos = start_pos + jnp.arange(T)
         page0 = start_pos // self.page
@@ -423,45 +445,53 @@ class PagedLM:
 
         def body(h, xs):
             lp, kp, vp = xs
-            x = common.apply_norm(cfg, lp["ln1"], h)
-            q, k, v = attn_mod._project_qkv(cfg, lp["attn"], x, x)
-            q = common.apply_rope(q, pos[None], freqs)
-            k = common.apply_rope(k, pos[None], freqs)
-            dest = jax.lax.dynamic_slice(rows, (page0,), (npage,))
-            dest = jnp.where(page0 + jnp.arange(npage) < n_alloc, dest,
-                             kp.shape[0])
-            kp = kp.at[dest].set(
-                k[0].reshape(npage, self.page, cfg.n_kv_heads, hd),
-                mode="drop")
-            vp = vp.at[dest].set(
-                v[0].reshape(npage, self.page, cfg.n_kv_heads, hd),
-                mode="drop")
-            kd = kp[rows].reshape(S_all, cfg.n_kv_heads, hd)
-            vd = vp[rows].reshape(S_all, cfg.n_kv_heads, hd)
-            qf = q[0].astype(jnp.float32) * hd ** -0.5
-            kf = kd.astype(jnp.float32)
-            vf = vd.astype(jnp.float32)
-            if group > 1:
-                kf = jnp.repeat(kf, group, axis=1)
-                vf = jnp.repeat(vf, group, axis=1)
-            logits = jnp.einsum("qhd,khd->hqk", qf, kf)
-            mask = jnp.arange(S_all)[None, :] <= pos[:, None]
-            logits = jnp.where(mask[None], logits, -jnp.inf)
-            probs = jax.nn.softmax(logits, axis=-1)
-            out = jnp.einsum("hqk,khd->qhd", probs, vf)
-            a = out.astype(h.dtype).reshape(1, T, -1) @ lp["attn"]["wo"]
-            h = h + a
-            x2 = common.apply_norm(cfg, lp["ln2"], h)
-            if cfg.moe is not None:
-                m, _ = moe_mod.apply_moe(cfg, lp["moe"], x2, dropless=True)
-            else:
-                m = common.apply_mlp(cfg, lp["mlp"], x2)
-            return h + m, (kp, vp)
+            with jax.named_scope("qkv"):
+                x = common.apply_norm(cfg, lp["ln1"], h)
+                q, k, v = attn_mod._project_qkv(cfg, lp["attn"], x, x)
+                q = common.apply_rope(q, pos[None], freqs)
+                k = common.apply_rope(k, pos[None], freqs)
+            with jax.named_scope("kv_write"):
+                dest = jax.lax.dynamic_slice(rows, (page0,), (npage,))
+                dest = jnp.where(page0 + jnp.arange(npage) < n_alloc, dest,
+                                 kp.shape[0])
+                kp = kp.at[dest].set(
+                    k[0].reshape(npage, self.page, cfg.n_kv_heads, hd),
+                    mode="drop")
+                vp = vp.at[dest].set(
+                    v[0].reshape(npage, self.page, cfg.n_kv_heads, hd),
+                    mode="drop")
+            with jax.named_scope("attention"):
+                kd = kp[rows].reshape(S_all, cfg.n_kv_heads, hd)
+                vd = vp[rows].reshape(S_all, cfg.n_kv_heads, hd)
+                qf = q[0].astype(jnp.float32) * hd ** -0.5
+                kf = kd.astype(jnp.float32)
+                vf = vd.astype(jnp.float32)
+                if group > 1:
+                    kf = jnp.repeat(kf, group, axis=1)
+                    vf = jnp.repeat(vf, group, axis=1)
+                logits = jnp.einsum("qhd,khd->hqk", qf, kf)
+                mask = jnp.arange(S_all)[None, :] <= pos[:, None]
+                logits = jnp.where(mask[None], logits, -jnp.inf)
+                probs = jax.nn.softmax(logits, axis=-1)
+                out = jnp.einsum("hqk,khd->qhd", probs, vf)
+            with jax.named_scope("attn_out"):
+                a = out.astype(h.dtype).reshape(1, T, -1) @ lp["attn"]["wo"]
+                h = h + a
+            with jax.named_scope("mlp"):
+                x2 = common.apply_norm(cfg, lp["ln2"], h)
+                if cfg.moe is not None:
+                    m, _ = moe_mod.apply_moe(cfg, lp["moe"], x2,
+                                             dropless=True)
+                else:
+                    m = common.apply_mlp(cfg, lp["mlp"], x2)
+                h = h + m
+            return h, (kp, vp)
 
         h, (k_pool, v_pool) = jax.lax.scan(body, h, (params["layers"],
                                                      k_pool, v_pool))
-        h = common.apply_norm(cfg, params["final_norm"], h)
-        logits = common.lm_head(cfg, params["embed"], h)
+        with jax.named_scope("head"):
+            h = common.apply_norm(cfg, params["final_norm"], h)
+            logits = common.lm_head(cfg, params["embed"], h)
         return logits, k_pool, v_pool
 
     def _decode_impl(self, params, tokens, k_pool, v_pool, page_table,
@@ -473,43 +503,52 @@ class PagedLM:
         cfg = self.cfg
         B = tokens.shape[0]
         hd = cfg.resolved_head_dim
-        h = common.embed_tokens(params["embed"], tokens)
+        with jax.named_scope("embed"):
+            h = common.embed_tokens(params["embed"], tokens)
         freqs = common.rope_freqs(cfg)
         pos = seq_lens  # (B,)
 
         def body(h, xs):
             lp, kp, vp = xs
-            x = common.apply_norm(cfg, lp["ln1"], h)
-            q, k, v = attn_mod._project_qkv(cfg, lp["attn"], x, x)
-            q = common.apply_rope(q, pos[:, None], freqs)
-            k = common.apply_rope(k, pos[:, None], freqs)
+            with jax.named_scope("qkv"):
+                x = common.apply_norm(cfg, lp["ln1"], h)
+                q, k, v = attn_mod._project_qkv(cfg, lp["attn"], x, x)
+                q = common.apply_rope(q, pos[:, None], freqs)
+                k = common.apply_rope(k, pos[:, None], freqs)
             # scatter this step's K/V into each slot's current page;
             # inactive slots scatter out-of-bounds (dropped — their pages
             # may already belong to a newly admitted request)
-            page_idx = pos // self.page
-            page_off = pos % self.page
-            phys = jnp.take_along_axis(page_table, page_idx[:, None],
-                                       axis=1)[:, 0]
-            phys = jnp.where(active, phys, kp.shape[0])
-            kp = kp.at[phys, page_off].set(k[:, 0], mode="drop")
-            vp = vp.at[phys, page_off].set(v[:, 0], mode="drop")
-            out = ops.paged_attention(q[:, 0], kp, vp, page_table,
-                                      seq_lens + 1)
-            a = out.reshape(B, 1, -1) @ lp["attn"]["wo"]
-            h = h + a
-            x2 = common.apply_norm(cfg, lp["ln2"], h)
-            if cfg.moe is not None:
-                m, _ = moe_mod.apply_moe(cfg, lp["moe"], x2, dropless=True)
-            else:
-                m = common.apply_mlp(cfg, lp["mlp"], x2)
-            return h + m, (kp, vp)
+            with jax.named_scope("kv_write"):
+                page_idx = pos // self.page
+                page_off = pos % self.page
+                phys = jnp.take_along_axis(page_table, page_idx[:, None],
+                                           axis=1)[:, 0]
+                phys = jnp.where(active, phys, kp.shape[0])
+                kp = kp.at[phys, page_off].set(k[:, 0], mode="drop")
+                vp = vp.at[phys, page_off].set(v[:, 0], mode="drop")
+            with jax.named_scope("attention"):
+                out = ops.paged_attention(q[:, 0], kp, vp, page_table,
+                                          seq_lens + 1)
+            with jax.named_scope("attn_out"):
+                a = out.reshape(B, 1, -1) @ lp["attn"]["wo"]
+                h = h + a
+            with jax.named_scope("mlp"):
+                x2 = common.apply_norm(cfg, lp["ln2"], h)
+                if cfg.moe is not None:
+                    m, _ = moe_mod.apply_moe(cfg, lp["moe"], x2,
+                                             dropless=True)
+                else:
+                    m = common.apply_mlp(cfg, lp["mlp"], x2)
+                h = h + m
+            return h, (kp, vp)
 
         h, (k_pool, v_pool) = jax.lax.scan(body, h,
                                            (params["layers"], k_pool,
                                             v_pool))
-        h = common.apply_norm(cfg, params["final_norm"], h)
-        logits = common.lm_head(cfg, params["embed"], h)[:, 0]
-        logits = jnp.where(active[:, None], logits, 0.0)
+        with jax.named_scope("head"):
+            h = common.apply_norm(cfg, params["final_norm"], h)
+            logits = common.lm_head(cfg, params["embed"], h)[:, 0]
+            logits = jnp.where(active[:, None], logits, 0.0)
         return logits, k_pool, v_pool
 
     # -- public API ---------------------------------------------------------------
@@ -549,12 +588,20 @@ class PagedLM:
         return int(jnp.argmax(logits[0, len(prompt) - 1 - start]))
 
     def decode_batch(self, tokens: np.ndarray, active: np.ndarray):
-        logits, self.k_pool, self.v_pool = self._decode(
-            self.params, jnp.asarray(tokens[:, None].astype(np.int32)),
-            self.k_pool, self.v_pool, jnp.asarray(self.page_table),
-            jnp.asarray(self.seq_lens), jnp.asarray(active))
+        """One decode step: dispatch the decode program (``engine/decode``),
+        then read each slot's next token back to the host
+        (``engine/sample``, where the host waits on the device)."""
+        n = int(active.sum())
+        with span("decode", step=self.steps, tokens=n):
+            logits, self.k_pool, self.v_pool = self._decode(
+                self.params, jnp.asarray(tokens[:, None].astype(np.int32)),
+                self.k_pool, self.v_pool, jnp.asarray(self.page_table),
+                jnp.asarray(self.seq_lens), jnp.asarray(active))
         self.seq_lens = self.seq_lens + active.astype(np.int32)
-        return np.asarray(jnp.argmax(logits, -1))
+        with span("sample", step=self.steps, tokens=n):
+            out = np.asarray(jnp.argmax(logits, -1))
+        self.steps += 1
+        return out
 
 
 class Engine:
@@ -565,6 +612,10 @@ class Engine:
     engine step), so a long prompt no longer stalls the running batch for
     its whole forward — the serving-side overlap engine.  Tokens are
     identical to whole-prompt prefill (same per-query attention math).
+
+    ``decode_stall_s`` counts host time in admission and chunk dispatch
+    (with the final chunk's readback) while a decode batch waits, not the
+    device time the batch waits behind the chunks.
     """
 
     def __init__(self, lm: PagedLM, *, chunked_prefill: bool = False,
@@ -578,8 +629,13 @@ class Engine:
         self.finished: list[Request] = []
         self.steps = 0
         self.prefill_chunks = 0
-        self.decode_stall_s = 0.0   # non-decode work while a batch waited
-        self._step_times: list[float] = []
+        # host seconds of steps that admitted or prefilled while a decode
+        # batch was running: ``_admit`` and chunk dispatch, with the final
+        # chunk's token readback.  Chunks run asynchronously, so this is
+        # not the device time the batch waited behind them: that shows in
+        # the profiler trace, as chunk programs between ``engine/sample``
+        # spans (the benchmark's ``itl_tail_chunk_share``).
+        self.decode_stall_s = 0.0
         # shared-timeline accounting (lm.sim attached): each decode step
         # injects the node's TP collective traffic as flows; the timeline
         # owner (the serving cluster) settles them per logical window
@@ -602,6 +658,7 @@ class Engine:
         return len(self.pending) + len(self.prefilling) + len(self.running)
 
     def submit(self, req: Request) -> None:
+        req.submitted = time.perf_counter()
         self.pending.append(req)
 
     # -- migration hooks (ServingCluster) ---------------------------------------
@@ -622,9 +679,16 @@ class Engine:
         while self.pending and len(self.running) + len(self.prefilling) \
                 < self.lm.max_batch:
             req = self.pending.pop(0)
+            waited_ms = (time.perf_counter() - req.submitted) * 1e3 \
+                if req.submitted is not None else -1.0
             try:
-                slot = self.lm.claim_slot(len(req.prompt),
-                                          req.max_new_tokens)
+                with (contextlib.nullcontext() if self.lm.modelled else
+                      span("claim", rid=req.rid, waited_ms=waited_ms)) \
+                        as claim:
+                    slot = self.lm.claim_slot(len(req.prompt),
+                                              req.max_new_tokens)
+                    if claim is not None:
+                        claim.set_metadata(slot=slot)
             except (RuntimeError, StopIteration):
                 self.pending.insert(0, req)
                 return admitted
@@ -662,8 +726,11 @@ class Engine:
         if self.lm.modelled:
             return self._advance_prefills_modelled()
         for slot, req in list(self.prefilling.items()):
-            tok = self.lm.prefill_slot_chunk(slot, req.prompt, req.pos,
-                                             self.chunk_tokens)
+            last = int(req.pos + self.chunk_tokens >= len(req.prompt))
+            with span("prefill_chunk", rid=req.rid, slot=slot,
+                      start=req.pos, last=last):
+                tok = self.lm.prefill_slot_chunk(slot, req.prompt, req.pos,
+                                                 self.chunk_tokens)
             self.prefill_chunks += 1
             chunks += 1
             req.pos = min(req.pos + self.chunk_tokens, len(req.prompt))
@@ -700,6 +767,12 @@ class Engine:
         return chunks
 
     def step(self) -> None:
+        with span("step", step=self.steps, pending=len(self.pending),
+                  prefilling=len(self.prefilling),
+                  running=len(self.running)):
+            self._step()
+
+    def _step(self) -> None:
         t0 = time.perf_counter()
         # fresh window accounting: the cluster steps each engine exactly
         # once per logical window and reads these at window close
@@ -712,16 +785,16 @@ class Engine:
         if self.chunked_prefill:
             worked += self._advance_prefills()
         if had_batch and worked:
-            # whole-prompt prefill (or the per-step chunk) ran while the
-            # decode batch sat idle: that gap is the admission stall the
-            # chunked path bounds at one chunk.  Steps that admitted or
-            # prefilled nothing did no non-decode work — the _admit walk
-            # itself is not a stall.
+            # host time of admission and prefill with a decode batch
+            # waiting: a whole-prompt prefill (which reads its token back),
+            # or the dispatch of this step's chunks and the final chunk's
+            # readback.  Steps that admitted or prefilled nothing did no
+            # non-decode work — the _admit walk itself is not counted.
             self.decode_stall_s += time.perf_counter() - t0
         if not self.running:
             return
         if self.lm.modelled:
-            self._step_modelled(t0)
+            self._step_modelled()
             return
         B = self.lm.max_batch
         tokens = np.zeros((B,), np.int32)
@@ -743,17 +816,20 @@ class Engine:
                 cls=fabric.TrafficClass.DECODE))
             self.sim_comm_steps += 1
         self.steps += 1
-        self._step_times.append(time.perf_counter() - t0)
-        for slot, req in list(self.running.items()):
+        for slot, req in self.running.items():
             if active[slot]:
                 req.out_tokens.append(int(nxt[slot]))
                 req.pos += 1
-            if req.done:
-                self.lm.free_slot(slot)
-                self.finished.append(self.running.pop(slot))
-                self.window_finished.append(req)
+        done = [slot for slot, req in self.running.items() if req.done]
+        if done:
+            with span("retire", finished=len(done)):
+                for slot in done:
+                    self.lm.free_slot(slot)
+                    req = self.running.pop(slot)
+                    self.finished.append(req)
+                    self.window_finished.append(req)
 
-    def _step_modelled(self, t0: float) -> None:
+    def _step_modelled(self) -> None:
         """Decode step on a modelled lm: token bookkeeping only (the
         placeholder token is 0), same batch/finish semantics as the real
         path; the window owner prices ``window_decode_tokens`` of compute
@@ -776,7 +852,6 @@ class Engine:
                 cls=fabric.TrafficClass.DECODE))
             self.sim_comm_steps += 1
         self.steps += 1
-        self._step_times.append(time.perf_counter() - t0)
 
     def settle_comm(self, window_start: float) -> float:
         """Resolve this window's injected TP flows against the shared
@@ -803,18 +878,14 @@ class Engine:
 
     def stats(self) -> dict:
         alloc = self.lm.allocator
-        # median, not mean: the first decode step carries jit compilation
-        measured = (float(np.median(self._step_times))
-                    if self._step_times else 0.0)
         return {
             "decode_steps": self.steps,
             "finished": len(self.finished),
             "tlb_hit_rate": alloc.hit_rate,
             "translation_cost_s": alloc.translation_cost,
-            # fabric CollectiveSchedule prediction vs wall clock: the
-            # per-step TP all-reduce cost a torus deployment would add
+            # fabric CollectiveSchedule prediction: the per-step TP
+            # all-reduce cost a torus deployment would add
             "predicted_tp_comm_s": self.lm.predicted_tp_comm_s,
-            "measured_step_s": measured,
             # overlap engine (serving side): chunked-prefill admission
             "chunked_prefill": self.chunked_prefill,
             "prefill_chunks": self.prefill_chunks,

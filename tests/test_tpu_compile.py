@@ -3,7 +3,8 @@
 Interpret mode (tests/test_kernels.py) checks the kernels' numbers but not
 the TPU's tiling rules; here the installed TPU compiler compiles each kernel
 for one chip of a described (not attached) v5e:2x2 topology and the
-compiled program must hold the kernel (``tpu_custom_call``).  The topology
+compiled program must hold the kernel (``tpu_custom_call``) under its
+name.  The topology
 is described inside a fixture, never while a module is imported: only one
 process at a time may load the TPU library.
 """
@@ -50,6 +51,13 @@ def _compiled_text(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _holds_kernel(text: str, name: str) -> bool:
+    """The program holds the Pallas kernel as a custom call named ``name``."""
+    return any(line.lstrip().startswith(f"%{name}") and
+               'custom_call_target="tpu_custom_call"' in line
+               for line in text.splitlines())
+
+
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
@@ -62,7 +70,7 @@ def test_paged_attention_compiles(one_chip, arch):
     text = _compiled_text(pa.paged_attention, one_chip,
                           ((B, H, D), BF16), (pool, BF16), (pool, BF16),
                           ((B, pages_per_seq), I32), ((B,), I32))
-    assert "tpu_custom_call" in text
+    assert _holds_kernel(text, "paged_attention")
 
 
 def test_flash_attention_compiles(one_chip):
@@ -71,7 +79,7 @@ def test_flash_attention_compiles(one_chip):
     kv = ((1, cfg.n_kv_heads, S, D), BF16)
     text = _compiled_text(fa.flash_attention, one_chip,
                           ((1, cfg.n_heads, S, D), BF16), kv, kv)
-    assert "tpu_custom_call" in text
+    assert _holds_kernel(text, "flash_attention")
 
 
 def test_mamba2_scan_compiles(one_chip):
@@ -82,7 +90,7 @@ def test_mamba2_scan_compiles(one_chip):
                           ((B, S, H, dh), BF16), ((B, S, H), BF16),
                           ((H,), F32), ((B, S, ds), BF16),
                           ((B, S, ds), BF16), ((H,), F32))
-    assert "tpu_custom_call" in text
+    assert _holds_kernel(text, "mamba2_scan")
 
 
 def test_rwkv6_scan_compiles(one_chip):
@@ -91,4 +99,32 @@ def test_rwkv6_scan_compiles(one_chip):
     seq = ((2, 2048, H, dh), BF16)
     text = _compiled_text(rw.rwkv6_scan, one_chip, seq, seq, seq, seq,
                           ((H, dh), F32))
-    assert "tpu_custom_call" in text
+    assert _holds_kernel(text, "rwkv6_scan")
+
+
+def test_decode_program_holds_the_named_kernel_in_its_scope(one_chip,
+                                                           monkeypatch):
+    """The engine's decode program, traced as on a TPU, holds the paged-
+    attention kernel by name inside its ``attention`` scope."""
+    import numpy as np
+    from repro.kernels import ops
+    from repro.models import api
+    from repro.serving.engine import PagedLM
+    monkeypatch.setattr(ops, "_use_pallas", lambda impl: (True, False))
+    cfg = configs.get_config("qwen2-0.5b").reduced()
+    lm = PagedLM(cfg, None, max_batch=8, max_seq=256, page_tokens=16,
+                 tp_axes=())
+    params = jax.eval_shape(
+        lambda: api.get_model(cfg).init(jax.random.key(0)))
+    pool = lm.k_pool.shape
+    B = lm.max_batch
+    shapes = [jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                  x.shape, x.dtype, sharding=one_chip), params)] + [
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+        (((B, 1), I32), (pool, cfg.dtype), (pool, cfg.dtype),
+         (lm.page_table.shape, I32), ((B,), I32), ((B,), np.bool_))]
+    text = jax.jit(lm._decode_impl).lower(*shapes).compile().as_text()
+    (line,) = [ln for ln in text.splitlines()
+               if ln.lstrip().startswith("%paged_attention")]
+    assert 'custom_call_target="tpu_custom_call"' in line
+    assert "/attention/paged_attention/" in line
