@@ -62,12 +62,18 @@ def flash_attention(q, k, v, *, causal: bool = True, scale=None,
     return ref.mha_attention(q, k, v, causal=causal, scale=scale)
 
 
-def paged_attention(q, k_pages, v_pages, page_table, seq_lens, *, scale=None,
-                    impl: Impl = "auto"):
+def paged_attention(q, k_pages, v_pages, page_table, seq_lens, *, layer=None,
+                    scale=None, impl: Impl = "auto"):
+    """Pools (P, page, Hkv, D), or with ``layer`` the stacked lane-dense
+    pools (L, P, page, Hkv*D) read at that layer."""
     use, interp = _use_pallas(impl)
     if use:
         return _pa.paged_attention(q, k_pages, v_pages, page_table, seq_lens,
-                                   scale=scale, interpret=interp)
+                                   layer=layer, scale=scale, interpret=interp)
+    if layer is not None:
+        D = q.shape[-1]
+        k_pages, v_pages = (p[layer].reshape(*p.shape[1:3], -1, D)
+                            for p in (k_pages, v_pages))
     return ref.paged_attention(q, k_pages, v_pages, page_table, seq_lens,
                                scale=scale)
 

@@ -1,7 +1,11 @@
 """Batched serving engine with a paged KV cache — the §2.2 TLB in action.
 
-The engine owns a physical page pool per layer; each request's logical
+The engine owns one physical page pool for K and one for V, each stacked
+over the layers and lane-dense, (L, P, page, Hkv*D); each request's logical
 (virtual) cache pages are mapped to physical pages through a page table.
+The jitted programs take the pools donated and carry them whole through the
+layer loop, so every write lands in place and nothing copies a layer's
+pages out of the stack.
 Page allocation goes through buffer *registration* on an RdmaEndpoint
 (core/rdma): the first touch of a page walks the "Nios II" path, later
 accesses hit the hardware TLB — the engine reports the measured hit rate
@@ -136,8 +140,9 @@ class SlotState:
     """A running slot's exportable KV state — what a migration moves.
 
     ``k``/``v`` hold only the slot's LIVE pages (the ones covering
-    ``seq_len`` tokens) in page-table (logical) order, shaped
-    (L, n_pages, page_tokens, n_kv_heads, head_dim) — the zero/stale
+    ``seq_len`` tokens) in page-table (logical) order, shaped like the
+    pool with its page axis cut to them, (L, n_pages, page_tokens,
+    n_kv_heads * head_dim) — the zero/stale
     ``max_new`` headroom pages never touch the wire; the importer claims
     all ``n_alloc`` pages fresh from its own pool (physical page ids are
     a node-local detail and do NOT travel).
@@ -204,12 +209,15 @@ class PagedLM:
         self.n_pages = pool_pages or int(need * 1.25)
         hd = cfg.resolved_head_dim
         L = cfg.n_layers
+        # a page's K (or V) for every KV head, one lane-dense row per token:
+        # for qwen2-0.5b a page is one bf16 (16, 128) tile, so the layer
+        # loop, its scatters and gathers and the kernel share one layout
+        self.pool_shape = (L, self.n_pages, page_tokens, cfg.n_kv_heads * hd)
         if modelled:
             self.k_pool = None
             self.v_pool = None
         else:
-            self.k_pool = jnp.zeros((L, self.n_pages, page_tokens,
-                                     cfg.n_kv_heads, hd), cfg.dtype)
+            self.k_pool = jnp.zeros(self.pool_shape, cfg.dtype)
             self.v_pool = jnp.zeros_like(self.k_pool)
         self.page_table = np.zeros((max_batch, self.pages_per_seq), np.int32)
         self.seq_lens = np.zeros((max_batch,), np.int32)
@@ -263,14 +271,12 @@ class PagedLM:
             self.predicted_tp_comm_s = 0.0
         self.slot_pages: dict[int, list[int]] = {}
         self.steps = 0               # decode steps run (``engine/decode``)
-        if modelled:
-            self._decode = None
-            self._prefill = None
-            self._prefill_chunk = None
-        else:
-            self._decode = jax.jit(self._decode_impl)
-            self._prefill = jax.jit(self._prefill_impl)
-            self._prefill_chunk = jax.jit(self._prefill_chunk_impl)
+        # the programs update the pools (arguments 2 and 3) in place; a
+        # modelled lm never calls them, so they never compile
+        pools = dict(donate_argnums=(2, 3))
+        self._decode = jax.jit(self._decode_impl, **pools)
+        self._prefill = jax.jit(self._prefill_impl, **pools)
+        self._prefill_chunk = jax.jit(self._prefill_chunk_impl, **pools)
 
     # -- fault feed -------------------------------------------------------------
     def relower_tp(self, faults) -> bool:
@@ -403,10 +409,8 @@ class PagedLM:
         k = cache["k"][:, 0]   # (L, S, Hkv, hd)
         v = cache["v"][:, 0]
         npage_prompt = S // self.page   # S is padded to page multiple
-        kp = k.reshape(cfg.n_layers, npage_prompt, self.page,
-                       cfg.n_kv_heads, -1)
-        vp = v.reshape(cfg.n_layers, npage_prompt, self.page,
-                       cfg.n_kv_heads, -1)
+        kp = k.reshape(cfg.n_layers, npage_prompt, self.page, -1)
+        vp = v.reshape(cfg.n_layers, npage_prompt, self.page, -1)
         dest = jax.lax.dynamic_slice(page_table, (slot, 0),
                                      (1, self.pages_per_seq))[0]
         k_pool = k_pool.at[:, dest[:npage_prompt]].set(kp)
@@ -443,8 +447,9 @@ class PagedLM:
         rows = jax.lax.dynamic_slice(page_table, (slot, 0),
                                      (1, self.pages_per_seq))[0]
 
-        def body(h, xs):
-            lp, kp, vp = xs
+        def body(carry, xs):
+            h, kp, vp = carry
+            lp, layer = xs
             with jax.named_scope("qkv"):
                 x = common.apply_norm(cfg, lp["ln1"], h)
                 q, k, v = attn_mod._project_qkv(cfg, lp["attn"], x, x)
@@ -453,16 +458,14 @@ class PagedLM:
             with jax.named_scope("kv_write"):
                 dest = jax.lax.dynamic_slice(rows, (page0,), (npage,))
                 dest = jnp.where(page0 + jnp.arange(npage) < n_alloc, dest,
-                                 kp.shape[0])
-                kp = kp.at[dest].set(
-                    k[0].reshape(npage, self.page, cfg.n_kv_heads, hd),
-                    mode="drop")
-                vp = vp.at[dest].set(
-                    v[0].reshape(npage, self.page, cfg.n_kv_heads, hd),
-                    mode="drop")
+                                 self.n_pages)
+                kp = kp.at[layer, dest].set(
+                    k[0].reshape(npage, self.page, -1), mode="drop")
+                vp = vp.at[layer, dest].set(
+                    v[0].reshape(npage, self.page, -1), mode="drop")
             with jax.named_scope("attention"):
-                kd = kp[rows].reshape(S_all, cfg.n_kv_heads, hd)
-                vd = vp[rows].reshape(S_all, cfg.n_kv_heads, hd)
+                kd = kp[layer, rows].reshape(S_all, cfg.n_kv_heads, hd)
+                vd = vp[layer, rows].reshape(S_all, cfg.n_kv_heads, hd)
                 qf = q[0].astype(jnp.float32) * hd ** -0.5
                 kf = kd.astype(jnp.float32)
                 vf = vd.astype(jnp.float32)
@@ -485,10 +488,11 @@ class PagedLM:
                 else:
                     m = common.apply_mlp(cfg, lp["mlp"], x2)
                 h = h + m
-            return h, (kp, vp)
+            return (h, kp, vp), None
 
-        h, (k_pool, v_pool) = jax.lax.scan(body, h, (params["layers"],
-                                                     k_pool, v_pool))
+        (h, k_pool, v_pool), _ = jax.lax.scan(
+            body, (h, k_pool, v_pool),
+            (params["layers"], jnp.arange(cfg.n_layers)))
         with jax.named_scope("head"):
             h = common.apply_norm(cfg, params["final_norm"], h)
             logits = common.lm_head(cfg, params["embed"], h)
@@ -502,14 +506,14 @@ class PagedLM:
         active: (B,) bool mask."""
         cfg = self.cfg
         B = tokens.shape[0]
-        hd = cfg.resolved_head_dim
         with jax.named_scope("embed"):
             h = common.embed_tokens(params["embed"], tokens)
         freqs = common.rope_freqs(cfg)
         pos = seq_lens  # (B,)
 
-        def body(h, xs):
-            lp, kp, vp = xs
+        def body(carry, xs):
+            h, kp, vp = carry
+            lp, layer = xs
             with jax.named_scope("qkv"):
                 x = common.apply_norm(cfg, lp["ln1"], h)
                 q, k, v = attn_mod._project_qkv(cfg, lp["attn"], x, x)
@@ -523,12 +527,14 @@ class PagedLM:
                 page_off = pos % self.page
                 phys = jnp.take_along_axis(page_table, page_idx[:, None],
                                            axis=1)[:, 0]
-                phys = jnp.where(active, phys, kp.shape[0])
-                kp = kp.at[phys, page_off].set(k[:, 0], mode="drop")
-                vp = vp.at[phys, page_off].set(v[:, 0], mode="drop")
+                phys = jnp.where(active, phys, self.n_pages)
+                kp = kp.at[layer, phys, page_off].set(k[:, 0].reshape(B, -1),
+                                                      mode="drop")
+                vp = vp.at[layer, phys, page_off].set(v[:, 0].reshape(B, -1),
+                                                      mode="drop")
             with jax.named_scope("attention"):
                 out = ops.paged_attention(q[:, 0], kp, vp, page_table,
-                                          seq_lens + 1)
+                                          seq_lens + 1, layer=layer)
             with jax.named_scope("attn_out"):
                 a = out.reshape(B, 1, -1) @ lp["attn"]["wo"]
                 h = h + a
@@ -540,11 +546,11 @@ class PagedLM:
                 else:
                     m = common.apply_mlp(cfg, lp["mlp"], x2)
                 h = h + m
-            return h, (kp, vp)
+            return (h, kp, vp), None
 
-        h, (k_pool, v_pool) = jax.lax.scan(body, h,
-                                           (params["layers"], k_pool,
-                                            v_pool))
+        (h, k_pool, v_pool), _ = jax.lax.scan(
+            body, (h, k_pool, v_pool),
+            (params["layers"], jnp.arange(cfg.n_layers)))
         with jax.named_scope("head"):
             h = common.apply_norm(cfg, params["final_norm"], h)
             logits = common.lm_head(cfg, params["embed"], h)[:, 0]

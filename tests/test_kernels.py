@@ -104,6 +104,39 @@ def test_paged_attention_matches_ref(B, H, Hkv, D, page, max_pages, pool,
     assert_close(got, want, dtype)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("L,B,H,Hkv,D,page,max_pages,pool", [
+    (3, 4, 4, 2, 32, 16, 4, 20),
+    (2, 3, 8, 1, 64, 8, 3, 12),
+])
+def test_paged_attention_on_the_stacked_pool_matches_ref_per_layer(
+        L, B, H, Hkv, D, page, max_pages, pool, dtype):
+    """Given the stacked lane-dense pool (L, P, page, Hkv*D) and a layer,
+    the kernel reads that layer's pages: equal to the reference on them,
+    for every layer, with ragged lengths and a slot that holds no token."""
+    q = jnp.asarray(RNG.normal(size=(B, H, D)), dtype)
+    kp = jnp.asarray(RNG.normal(size=(L, pool, page, Hkv * D)), dtype)
+    vp = jnp.asarray(RNG.normal(size=(L, pool, page, Hkv * D)), dtype)
+    pt = jnp.asarray(RNG.permutation(pool)[:B * max_pages].reshape(
+        B, max_pages).astype(np.int32))
+    sl = RNG.integers(1, page * max_pages + 1, size=B).astype(np.int32)
+    sl[0], sl[-1] = page + 1, 0           # one page and a token; inactive
+    live = sl > 0
+    sl = jnp.asarray(sl)
+    for layer in range(L):
+        pages = (lambda p: p[layer].reshape(pool, page, Hkv, D))
+        want = ref.paged_attention(q, pages(kp), pages(vp), pt, sl)
+        got = paged_attention(q, kp, vp, pt, sl, layer=jnp.int32(layer),
+                              interpret=True)
+        assert got.dtype == dtype
+        assert_close(got[live], want[live], dtype)
+        assert not np.asarray(got[~live], np.float32).any()
+        no_kernel = ops.paged_attention(q, kp, vp, pt, sl, layer=layer,
+                                        impl="ref")
+        np.testing.assert_array_equal(np.asarray(no_kernel[live]),
+                                      np.asarray(want[live]))
+
+
 def test_paged_attention_ignores_unmapped_pages():
     """Pages past seq_len must not influence the result even if the page
     table points at garbage there (RDMA safety: no reads beyond the

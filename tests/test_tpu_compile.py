@@ -4,11 +4,14 @@ Interpret mode (tests/test_kernels.py) checks the kernels' numbers but not
 the TPU's tiling rules; here the installed TPU compiler compiles each kernel
 for one chip of a described (not attached) v5e:2x2 topology and the
 compiled program must hold the kernel (``tpu_custom_call``) under its
-name.  The topology
+name.  The serving engine's decode and prefill-chunk programs are compiled
+at the chat cell's sizes, to show that they update the stacked KV pools in
+place.  The topology
 is described inside a fixture, never while a module is imported: only one
 process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +76,22 @@ def test_paged_attention_compiles(one_chip, arch):
     assert _holds_kernel(text, "paged_attention")
 
 
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "deepseek-7b"])
+def test_paged_attention_compiles_on_the_stacked_pool(one_chip, arch):
+    """The kernel reads one layer of the engine's stacked lane-dense pool,
+    the layer a traced scalar as in the engine's layer loop."""
+    cfg = configs.get_config(arch)
+    B, pages_per_seq, page, L = 32, 256, 16, 4
+    H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    pool = (L, B * pages_per_seq, page, Hkv * D)
+    text = _compiled_text(
+        lambda q, kp, vp, pt, sl, ly: pa.paged_attention(q, kp, vp, pt, sl,
+                                                         layer=ly),
+        one_chip, ((B, H, D), BF16), (pool, BF16), (pool, BF16),
+        ((B, pages_per_seq), I32), ((B,), I32), ((), I32))
+    assert _holds_kernel(text, "paged_attention")
+
+
 def test_flash_attention_compiles(one_chip):
     cfg = configs.get_config("qwen2-0.5b")
     S, D = 2048, cfg.resolved_head_dim
@@ -125,6 +144,105 @@ def test_decode_program_holds_the_named_kernel_in_its_scope(one_chip,
          (lm.page_table.shape, I32), ((B,), I32), ((B,), np.bool_))]
     text = jax.jit(lm._decode_impl).lower(*shapes).compile().as_text()
     (line,) = [ln for ln in text.splitlines()
+               if ln.lstrip().startswith("%paged_attention")]
+    assert 'custom_call_target="tpu_custom_call"' in line
+    assert "/attention/paged_attention/" in line
+
+
+# -- the serving programs at the chat cell's sizes ---------------------------
+
+CELL = dict(arch="qwen2-0.5b", max_batch=128, max_seq=4096, page_tokens=16,
+            pool_pages=10240, chunk_tokens=256)
+_SHAPE = re.compile(r"\b(pred|[bsuf](?:f)?\d+)\[([\d,]*)\]")
+_INSTR = re.compile(r"\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.+?)\s+([\w\-]+)\(")
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _array_bytes(dtype: str, dims: str) -> int:
+    width = 1 if dtype == "pred" else int(re.sub(r"\D", "", dtype)) // 8
+    n = 1
+    for d in filter(None, dims.split(",")):
+        n *= int(d)
+    return n * width
+
+
+def _moves_at_least(text: str, nbytes: int) -> list[str]:
+    """Every copy, dynamic-slice or dynamic-update-slice, alone or as a
+    fusion named for one, anywhere in the program (fused bodies included)
+    whose largest result array holds ``nbytes`` or more."""
+    out = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, result, opcode = m.groups()
+        if not (opcode.startswith(_MOVES) or
+                (opcode == "fusion" and any(w in name for w in _MOVES))):
+            continue
+        if max((_array_bytes(*a) for a in _SHAPE.findall(result)),
+               default=0) >= nbytes:
+            out.append(f"{name} = {result[:60]} {opcode}")
+    return out
+
+
+@pytest.fixture(scope="module")
+def chat_cell_programs(one_chip):
+    """The decode and prefill-chunk programs the engine runs in the chat
+    cell, donation included, compiled for a v5e chip as a TPU traces them
+    (through the Pallas kernel).  A modelled PagedLM allocates no pools."""
+    import numpy as np
+    from repro.kernels import ops
+    from repro.models import api
+    from repro.serving.engine import PagedLM
+    cfg = configs.get_config(CELL["arch"])
+    lm = PagedLM(cfg, None, max_batch=CELL["max_batch"],
+                 max_seq=CELL["max_seq"], page_tokens=CELL["page_tokens"],
+                 pool_pages=CELL["pool_pages"], tp_axes=(), modelled=True)
+    params = jax.eval_shape(
+        lambda: api.get_model(cfg).init(jax.random.key(0)))
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    p = jax.tree.map(lambda x: sds(x.shape, x.dtype), params)
+    pool, table, B = lm.pool_shape, lm.page_table.shape, lm.max_batch
+    args = {
+        "decode": (lm._decode, [p, sds((B, 1), I32), sds(pool, cfg.dtype),
+                                sds(pool, cfg.dtype), sds(table, I32),
+                                sds((B,), I32), sds((B,), np.bool_)]),
+        "chunk": (lm._prefill_chunk,
+                  [p, sds((1, CELL["chunk_tokens"]), I32),
+                   sds(pool, cfg.dtype), sds(pool, cfg.dtype),
+                   sds(table, I32), sds((), I32), sds((), I32),
+                   sds((), I32)]),
+    }
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_use_pallas", lambda impl: (True, False))
+        compiled = {k: fn.lower(*a).compile() for k, (fn, a) in args.items()}
+    pool_bytes = int(np.prod(pool)) * jnp.dtype(cfg.dtype).itemsize
+    return compiled, pool_bytes, pool_bytes // pool[0]
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_serving_program_aliases_both_pools(chat_cell_programs, program):
+    compiled, pool_bytes, _ = chat_cell_programs
+    alias = compiled[program].memory_analysis().alias_size_in_bytes
+    assert alias >= 2 * pool_bytes, (alias, 2 * pool_bytes)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_serving_program_moves_no_layer_of_the_pool(chat_cell_programs,
+                                                    program):
+    """No op copies, slices or re-stacks a layer's pages (or more): the
+    layer loop writes and reads the stacked pools where they lie."""
+    compiled, _, layer_bytes = chat_cell_programs
+    text = compiled[program].as_text()
+    assert _moves_at_least(text, layer_bytes) == []
+    # the reading sees the program's moves: the small ones are there
+    assert _moves_at_least(text, 1) != []
+
+
+def test_decode_program_at_the_chat_cell_runs_the_kernel_in_its_scope(
+        chat_cell_programs):
+    compiled, _, _ = chat_cell_programs
+    (line,) = [ln for ln in compiled["decode"].as_text().splitlines()
                if ln.lstrip().startswith("%paged_attention")]
     assert 'custom_call_target="tpu_custom_call"' in line
     assert "/attention/paged_attention/" in line
