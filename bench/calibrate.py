@@ -8,7 +8,7 @@ and the driver's ``control`` readings on the same work: the reference
 computed a precision below the configuration's (``quant="fp8"``) in the
 program's place, and for training the faults planted in the reference.
 Each limit lies above every program reading and below the readings it
-must fail (PERF.md gives them); a serving control's gap is also judged
+must fail (PERF.md gives them); the control's readings are also judged
 by the run's own checks (``control_correct``, which must be false).
 Prints one JSON line per seed.
 """

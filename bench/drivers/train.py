@@ -20,6 +20,7 @@ import harness
 import weights
 
 CHECKED_STEPS = 3
+COMPARED = ("loss_gap", "grad_norm_gap", "change_norm_gap")
 
 
 class Feed:
@@ -94,11 +95,11 @@ def first_steps(ctx, tr) -> dict:
 def compare(prog: dict, ref: dict) -> dict:
     """The numbers a run holds against its limits: the largest relative
     gap of the three losses, and by the worst leaf the gap of the first
-    gradient's norm, against the larger of that leaf's reference norm and
-    the median leaf's.  Also, not compared (PERF.md gives why), the same
-    gap of each leaf's change over the three steps; leaves whose reference
-    gradient is under a thousandth of the median leaf's are left out of
-    it (they move by round-off alone).  ``worst`` names the leaves."""
+    gradient's norm and of each leaf's change over the three steps, each
+    against the larger of that leaf's reference norm and the median
+    leaf's.  Leaves whose reference gradient is under a thousandth of the
+    median leaf's are left out of the change (they move by round-off
+    alone).  ``worst`` names the leaves."""
     loss_gap = max(abs(a - b) / abs(b)
                    for a, b in zip(prog["losses"], ref["losses"]))
     gmed = float(np.median(list(ref["grad_norms"].values())))
@@ -112,6 +113,12 @@ def compare(prog: dict, ref: dict) -> dict:
     return {"loss_gap": loss_gap, "grad_norm_gap": grad[worst_g],
             "change_norm_gap": change[worst_c],
             "worst": {"grad": worst_g, "change": worst_c}}
+
+
+def run_checks(readings: dict, limits: dict) -> list:
+    """The compared numbers, each beside its limit."""
+    return [{"name": k, "value": readings[k], "limit": limits[k]}
+            for k in COMPARED]
 
 
 def reference_readings(ctx, batches, **kw) -> dict:
@@ -153,12 +160,9 @@ def run(ctx) -> dict:
     gc.collect()
     ref = reference_readings(ctx, batches)
     readings = compare(prog, ref)
-    harness.log(f"not compared: change_norm_gap {readings['change_norm_gap']}"
-                f" (worst leaf {readings['worst']['change']}); worst leaf of "
-                f"grad_norm_gap {readings['worst']['grad']}")
-    limits = ctx.cfg["check"]
-    checks = [{"name": k, "value": readings[k], "limit": limits[k]}
-              for k in ("loss_gap", "grad_norm_gap")]
+    harness.log(f"worst leaf of grad_norm_gap {readings['worst']['grad']}, "
+                f"of change_norm_gap {readings['worst']['change']}")
+    checks = run_checks(readings, ctx.cfg["check"])
     tokens = steps * rows * seq
     return {"attempted": steps, "failed": 0,
             "e2e": {"train_tokens_per_s": (tokens / (t1 - t0), "tokens/s")},
@@ -173,7 +177,9 @@ def run(ctx) -> dict:
 def control(ctx, out) -> dict:
     """Readings of the control (the reference in fp8) and of the faults
     planted in the reference, against the run's reference, on the same
-    batches: what the limits must separate from the program's."""
+    batches: what the limits must separate from the program's.  The
+    control's readings also go through the run's own checks
+    (``control_checks``)."""
     ref_mod = harness.plugin("reference", ctx.cfg["reference"])
     cfg = ctx.cfg
     rows = cfg["deployment"]["per_chip_batch"] * len(ctx.devices)
@@ -186,4 +192,5 @@ def control(ctx, out) -> dict:
                      ("no_exchange", {"fault": "no_exchange",
                                       "ranks": len(ctx.devices)})):
         readings[name] = compare(reference_readings(ctx, batches, **kw), ref)
+    readings["control_checks"] = run_checks(readings["control"], cfg["check"])
     return readings

@@ -277,11 +277,21 @@ def learning_rate(opt: dict, step: int) -> float:
     return opt["lr"] * warm * frac
 
 
+def _stored(x, dtype):
+    """``x`` rounded to ``dtype``, kept in float32.  By ``reduce_precision``
+    and not a float32 -> ``dtype`` -> float32 convert pair: on TPU, XLA's
+    excess-precision simplification drops such a pair, which left the
+    parameters in float32 across steps."""
+    f = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(x, exponent_bits=f.nexp,
+                                    mantissa_bits=f.nmant)
+
+
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2, 3, 4))
 def _adamw(opt_items, params, m, v, g, step, lr):
     """One AdamW update (no clipping).  Weight decay applies to leaves of
     two or more dimensions as stored (layers stacked on a leading axis);
-    parameters are kept in ``store_dtype`` between steps."""
+    parameters are rounded to ``store_dtype`` after every step."""
     opt = dict(opt_items)
     b1, b2 = opt["b1"], opt["b2"]
     bc1 = 1 - b1 ** step
@@ -294,7 +304,7 @@ def _adamw(opt_items, params, m, v, g, step, lr):
         delta = (m / bc1) / (jnp.sqrt(v / bc2) + opt["eps"])
         if opt["weight_decay"] and p.ndim >= 2:
             delta = delta + opt["weight_decay"] * p
-        return (p - lr * delta).astype(store).astype(jnp.float32), m, v
+        return _stored(p - lr * delta, store), m, v
 
     out = jax.tree.map(upd, params, m, v, g)
     pick = (lambda i: jax.tree.map(lambda _, o: o[i], params, out))

@@ -123,12 +123,18 @@ def test_exposed_collective_share_by_hand():
     assert got == pytest.approx(100 * (10 / 100 + 0 / 100) / 2)
 
 
-def test_train_mfu_by_hand():
+@pytest.mark.parametrize("steps", [10, 0])
+def test_train_mfu_by_hand(steps):
+    """The window's tokens over its seconds against every chip's peak; a
+    window with no whole step has nothing to read."""
     cfg = harness.load_json(harness.BENCH / "configs"
                             / "qwen2-0.5b-train-dp4.json")
-    rec = {"steps": 10, "tokens_per_step": 32768, "window_s": 5.0,
+    rec = {"steps": steps, "tokens_per_step": 32768, "window_s": 5.0,
            "chips": 4}
     r = bench_run.Reading(cfg, {"seq_len": 2048}, rec, None, PEAKS)
+    if not steps:
+        assert _read("train_mfu", r) is None
+        return
     per_token = flops.train_flops_per_token(cfg["model"], 2048)
     want = 100 * per_token * (10 * 32768 / 5.0) / (4 * 197e12)
     assert _read("train_mfu", r) == pytest.approx(want)

@@ -80,3 +80,27 @@ def test_served_gaps_are_zero_for_the_reference_own_tokens():
     bad[2] = (bad[2] + 1) % m["vocab_size"]
     assert float(REF.served_gaps(m, params, prompt, bad, seq_pad=32,
                                  rows_pad=8)[2]) > 1e-3
+
+
+@pytest.mark.parametrize("store", ["bfloat16", "float32"])
+def test_adamw_rounds_stored_parameters_by_an_op_the_compiler_keeps(store):
+    """Each update leaves the parameters on the ``store_dtype`` grid, by
+    ``reduce_precision``: a float32 -> bfloat16 -> float32 convert pair is
+    dropped by XLA's excess-precision simplification on TPU."""
+    opt = {"b1": 0.9, "b2": 0.95, "eps": 1e-8, "weight_decay": 0.0,
+           "store_dtype": store}
+    rng = np.random.default_rng(1)
+    # norm scales near 1: a step of lr = 3e-4 is under half a bfloat16 ulp
+    p = jnp.asarray(1 + rng.standard_normal((64, 32)) * 0.1, jnp.float32)
+    p = p.astype(store).astype(jnp.float32)
+    before = np.asarray(p)          # the update consumes its inputs
+    g = jnp.asarray(rng.standard_normal((64, 32)) * 1e-3, jnp.float32)
+    args = (REF._items(opt), {"w": p}, {"w": jnp.zeros_like(p)},
+            {"w": jnp.zeros_like(p)}, {"w": g}, jnp.float32(1),
+            jnp.float32(3e-4))
+    assert "reduce_precision" in REF._adamw.lower(*args).as_text()
+    new = np.asarray(REF._adamw(*args)[0]["w"])
+    on_grid = np.asarray(jnp.asarray(new).astype(store).astype(jnp.float32))
+    np.testing.assert_array_equal(new, on_grid)
+    moved = np.mean(new != before)
+    assert moved == (0.0 if store == "bfloat16" else 1.0)

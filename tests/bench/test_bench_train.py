@@ -37,12 +37,25 @@ def test_control_and_faults_in_the_reference_fail(cases, case):
     assert not cases[case]["correct"], cases[case]["readings"]
 
 
+def test_the_control_goes_through_the_runs_own_checks(cases):
+    """The driver's ``control_checks``: the control's reading of each
+    compared number beside the run's own limit."""
+    sound, control = cases["sound"], cases["reference_control"]
+    got = {c["name"]: c for c in control["checks"]}
+    assert set(got) == set(sound["readings"])
+    for name, check in got.items():
+        assert check["value"] == control["readings"][name]
+    assert {n: c["limit"] for n, c in got.items()} == sound["limits"]
+
+
 @pytest.mark.parametrize("case", ["unchanged", "half_batch", "no_exchange"])
 def test_faults_under_the_window_fail(cases, case):
     assert not cases[case]["correct"], cases[case]["readings"]
 
 
 def test_a_state_left_unchanged_reads_one(cases):
-    """The first moment stays zero: the first gradient reads 1 off."""
+    """The first moment stays zero and no parameter moves: the first
+    gradient and the change each read 1 off."""
     r = cases["unchanged"]["readings"]
     assert r["grad_norm_gap"] == pytest.approx(1.0)
+    assert r["change_norm_gap"] == pytest.approx(1.0)

@@ -90,14 +90,17 @@ def main() -> int:
         print(json.dumps({
             "case": name, "correct": bench_run.correct_from(out["checks"]),
             "readings": {c["name"]: c["value"] for c in out["checks"]},
+            "limits": {c["name"]: c["limit"] for c in out["checks"]},
             "steps": out["attempted"]}), flush=True)
         if name == "sound":     # the control and the planted faults
-            for case, readings in driver.control(ctx, out).items():
-                checks = [{"name": k, "value": readings[k],
-                           "limit": cfg["check"][k]}
-                          for k in ("loss_gap", "grad_norm_gap")]
+            control = driver.control(ctx, out)
+            control_checks = control.pop("control_checks")
+            for case, readings in control.items():
+                checks = (control_checks if case == "control" else
+                          driver.run_checks(readings, cfg["check"]))
                 print(json.dumps({
                     "case": f"reference_{case}", "readings": readings,
+                    "checks": checks,
                     "correct": bench_run.correct_from(checks)}), flush=True)
     return 0
 
